@@ -15,8 +15,9 @@ fails CI instead of silently rotting the docs:
   to a module under ``src/repro`` (or ``benchmarks``/``tests``), and
   every trailing attribute must appear in that module's source;
 * **CLI flags** — ``--hotset-bytes`` mentioned in docs must be the
-  literal string ``"--hotset-bytes"`` somewhere in the repo's .py
-  files (i.e. an argparse flag that still exists).
+  literal string ``"--hotset-bytes"`` in a .py file under src/,
+  benchmarks/ or the repo root (i.e. an argparse flag that still
+  exists).
 
 Deliberately NOT checked: bare prose words and un-dotted class names —
 too many false positives. Precision over recall: everything this
@@ -65,12 +66,13 @@ def _code_spans(text: str) -> list[str]:
 
 def _defined_flags() -> set[str]:
     flags: set[str] = set()
-    for base in (ROOT / "src", ROOT / "benchmarks"):
-        for py in base.rglob("*.py"):
-            flags.update(
-                m.group(0).strip("\"'")
-                for m in re.finditer(r"[\"']--[a-z][a-z0-9-]+[\"']", py.read_text())
-            )
+    scripts = [*ROOT.glob("*.py"), *(ROOT / "src").rglob("*.py"),
+               *(ROOT / "benchmarks").rglob("*.py")]
+    for py in scripts:
+        flags.update(
+            m.group(0).strip("\"'")
+            for m in re.finditer(r"[\"']--[a-z][a-z0-9-]+[\"']", py.read_text())
+        )
     return flags
 
 
@@ -147,7 +149,8 @@ def check_file(md: Path) -> list[str]:
                 err(bad)
         for m in _FLAG_RE.finditer(span):
             if m.group(0) not in flags_defined:
-                err(f"flag not defined anywhere in src/ or benchmarks/: {m.group(0)}")
+                err(f"flag not defined in src/, benchmarks/ or a root "
+                    f"script: {m.group(0)}")
     return errors
 
 

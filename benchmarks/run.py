@@ -204,6 +204,8 @@ def main() -> None:
     unknown = [s for s in picked if s not in SUITES]
     if unknown:
         ap.error(f"unknown suites {unknown}; available: {', '.join(SUITES)}")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     t0 = time.time()
     for name in picked:

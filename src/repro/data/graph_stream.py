@@ -328,9 +328,9 @@ class GraphStream:
             v0, v1, offs, packed, b = payload
             padded, n = pad_packed_for_stream(packed, b, granule=self._granule)
             nbr_shard, off_shard = place(len(padded) // b)
-            dev_packed = jnp.asarray(padded)          # H2D: packed bytes only
-            if nbr_shard is not None:
-                dev_packed = jax.device_put(dev_packed, nbr_shard)
+            # H2D: packed bytes only, straight to their placement; a
+            # stream split over "data" decodes on each device's own slice
+            dev_packed = jax.device_put(padded, nbr_shard)
             decoded = compbin_decode(dev_packed, b)   # eq. (1) on device
             neighbors = decoded[:n]
             h2d = padded.nbytes

@@ -29,19 +29,10 @@ from repro.models.transformer import TransformerConfig
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=False)``; older
-    releases ship it as ``jax.experimental.shard_map`` with the flag named
-    ``check_rep``.  Every caller in this repo wants the check disabled
-    (psum-carrying train steps), so that's baked in.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off: every caller in
+    this repo wants the check disabled (psum-carrying train steps)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_size(mesh: Mesh, name: str) -> int:

@@ -42,7 +42,7 @@ def _decode_kernel(packed_ref, out_ref, *, b: int):
 
 @functools.partial(jax.jit, static_argnames=("b", "block_rows", "interpret"))
 def compbin_decode_planar(planar: jnp.ndarray, *, b: int, block_rows: int = 256,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool) -> jnp.ndarray:
     """Decode a planar-packed stream uint8[rows, 128*b] -> int32[rows, 128].
 
     ``rows`` must be a multiple of ``block_rows`` (ops.py pads).

@@ -3,10 +3,13 @@
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.compbin_decode.kernel import compbin_decode_planar
 from repro.kernels.compbin_decode.ref import compbin_decode_ref
@@ -73,6 +76,35 @@ def _decode_impl(packed: jnp.ndarray, b: int, n: int, block_rows: int,
     return out.reshape(-1)[:n]
 
 
+@functools.partial(jax.jit, static_argnames=("b", "block_rows", "interpret",
+                                             "mesh", "axis"))
+def _decode_sharded(packed: jnp.ndarray, b: int, block_rows: int,
+                    interpret: bool, mesh: Mesh, axis) -> jnp.ndarray:
+    """:func:`_decode_impl` on every device of ``axis`` over its own
+    contiguous slice of the stream.  Mosaic kernels cannot be partitioned
+    automatically, so the split is explicit: each slice holds whole IDs
+    and decodes to the matching slice of the output."""
+    def local(part):
+        return _decode_impl(part, b, part.size // b, block_rows, interpret)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis), check_vma=False)(packed)
+
+
+def _split_axis(packed) -> tuple[Mesh, object, int] | None:
+    """(mesh, axis, devices) when ``packed`` is a concrete array split
+    over more than one device along its first dimension, else None."""
+    sharding = getattr(packed, "sharding", None)   # tracers carry none
+    if not isinstance(sharding, NamedSharding) or not sharding.spec:
+        return None
+    axis = sharding.spec[0]
+    if axis is None:
+        return None
+    names = axis if isinstance(axis, tuple) else (axis,)
+    d = math.prod(sharding.mesh.shape[a] for a in names)
+    return (sharding.mesh, axis, d) if d > 1 else None
+
+
 def decode_packed_stream(raw: np.ndarray, b: int, *,
                          block_rows: int = 256,
                          interpret: bool | None = None,
@@ -112,10 +144,18 @@ def compbin_decode(packed: jnp.ndarray, b: int, *, block_rows: int = 256,
     the zero high bytes are stripped before the kernel so only 4 byte
     planes cross into VMEM.  IDs >= 2^31 must take the host decode path
     (core.policy.choose_stream_decode routes them there).
+
+    A stream split over several devices along a mesh axis (the streaming
+    loader's ``P("data")`` placement) decodes on each device over its own
+    slice, and the output is split the same way.
     """
     if not 1 <= b <= 8:
         raise ValueError(f"b must be in [1,8] for device decode, got {b}")
     n = packed.size // b
+    split = _split_axis(packed)
+    if split is not None and n % split[2]:
+        raise ValueError(f"{n} IDs do not split evenly over {split[2]} "
+                         f"devices of mesh axis {split[1]!r}")
     if b > 4:
         packed = jnp.asarray(packed).reshape(n, b)
         try:
@@ -135,6 +175,10 @@ def compbin_decode(packed: jnp.ndarray, b: int, *, block_rows: int = 256,
         return compbin_decode_ref(packed.reshape(-1), b)
     if interpret is None:
         interpret = interpret_default()
+    if split is not None:
+        mesh, axis, _ = split
+        return _decode_sharded(packed.reshape(-1), b, block_rows, interpret,
+                               mesh, axis)
     return _decode_impl(packed.reshape(-1), b, n, block_rows, interpret)
 
 

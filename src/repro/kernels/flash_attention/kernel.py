@@ -80,7 +80,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     "causal", "scale", "block_q", "block_k", "offset", "kv_len", "interpret"))
 def flash_attention_blocked(q, k, v, *, causal: bool, scale: float,
                             block_q: int, block_k: int, offset: int,
-                            kv_len: int, interpret: bool = True):
+                            kv_len: int, interpret: bool):
     """q: [BHq, Sq, Dh]; k, v: [BHkv, Skv, Dh] with BHq = B*Hq flattened and
     the GQA group size inferred as BHq // BHkv.  Shapes pre-padded;
     ``offset``/``kv_len`` carry the unpadded alignment (see _flash_kernel)."""

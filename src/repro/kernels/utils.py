@@ -2,8 +2,6 @@
 
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
 
@@ -23,15 +21,19 @@ def pad_to_multiple(x, multiple: int, axis: int, value=0):
 
 
 def interpret_default() -> bool:
-    """Kernels run in interpret mode unless a real TPU backend is present.
+    """Whether Pallas kernels run in interpret mode on this backend.
 
-    The container is CPU-only; ``interpret=True`` executes the kernel body in
-    Python for correctness validation, while the same ``pallas_call`` lowers
-    to Mosaic on a real TPU.  Override with REPRO_PALLAS_INTERPRET=0/1.
+    ``tpu`` compiles every kernel with Mosaic (False).  ``cpu`` has no
+    Mosaic compiler, so kernels run their bodies in interpret mode there
+    (True) — the tests and CPU rehearsals.  Any other backend has no
+    path for these kernels and raises rather than silently interpreting.
     """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     import jax
 
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels compile for 'tpu' and interpret on "
+                       f"'cpu'; backend {backend!r} has neither path")

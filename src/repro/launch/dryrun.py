@@ -13,9 +13,12 @@ Usage:
 cannot take down the sweep) and merges results incrementally into --out.
 """
 
-# The VERY FIRST two lines — before ANY other import — jax locks the device
-# count on first init (system-prompt contract for this dry-run):
+# Before ANY other import — jax locks the platform and the device count on
+# first init.  The dry-run compiles for 512 placeholder CPU devices, so it
+# stays off any accelerator: neither this process nor the per-cell
+# children it starts (they inherit the environment) may hold a chip.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse      # noqa: E402

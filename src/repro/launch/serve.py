@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 
 log = logging.getLogger("repro.serve")
 
@@ -483,6 +484,7 @@ def main() -> None:
                          "log the per-tier attribution report on exit "
                          "(0: tracing off, the no-op tracer)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     cfg = spec.make_reduced() if args.reduced else spec.make_config()

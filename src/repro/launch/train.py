@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.distributed.fault_tolerance import ResilientTrainer, StragglerMonitor
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          ef_compress_psum, ef_state_init)
@@ -285,6 +286,7 @@ def main() -> None:
     ap.add_argument("--inject-failure-at", type=int, default=None)
     ap.add_argument("--workdir", default="/tmp/repro_train")
     args = ap.parse_args()
+    enable_compile_cache()
 
     os.makedirs(args.workdir, exist_ok=True)
     spec = get_arch(args.arch)
