@@ -25,12 +25,14 @@ import dataclasses
 import os
 import queue
 import threading
+import time
 from typing import Callable, Iterable, Optional, Sequence, Union
 
 import numpy as np
 
 from repro.core import codec, pgfuse, webgraph
 from repro.core.csr import CSR
+from repro.obs.trace import PROFILER_TRACER
 
 FORMAT_COMPBIN = "compbin"
 FORMAT_WEBGRAPH = "webgraph"
@@ -52,12 +54,14 @@ def detect_format(path: Union[str, os.PathLike]) -> str:
 class PartitionBuffer:
     """One reusable producer->consumer buffer (paper's shared buffers)."""
 
+    part: int = 0                           # index in the read's plan
     v0: int = 0
     v1: int = 0
     offsets: Optional[np.ndarray] = None    # local, rebased to 0
     neighbors: Optional[np.ndarray] = None  # decoded IDs (raw=False)
     packed: Optional[np.ndarray] = None     # undecoded CompBin bytes (raw=True)
     b: int = 0                              # bytes/ID of ``packed``
+    read_s: float = 0.0                     # seconds the read took
     error: Optional[BaseException] = None
 
 
@@ -201,6 +205,8 @@ class GraphHandle:
         n_buffers: int = 4,
         n_workers: int = 4,
         raw: bool = False,
+        tracer=None,
+        trace_root=None,
     ) -> "AsyncRead":
         """Decode ``partitions`` concurrently; invoke ``callback(buffer)`` for
         each as it completes (possibly out of order).  The pool of
@@ -209,9 +215,15 @@ class GraphHandle:
 
         ``raw=True`` (CompBin only) skips host decode: each buffer carries
         ``packed``/``b`` instead of ``neighbors`` — the streaming loader's
-        storage stage (data/graph_stream.py)."""
+        storage stage (data/graph_stream.py).
+
+        Each read is a ``stream.read`` span of ``tracer`` (default
+        :data:`repro.obs.trace.PROFILER_TRACER`) on its producer thread,
+        under ``trace_root`` (a span the producers attach to), and its
+        PG-Fuse reads are ``pgfuse.read`` spans of the same tracer."""
         return AsyncRead(self, list(partitions), callback,
-                         n_buffers=n_buffers, n_workers=n_workers, raw=raw)
+                         n_buffers=n_buffers, n_workers=n_workers, raw=raw,
+                         tracer=tracer, trace_root=trace_root)
 
     def partition_plan(self, n_parts: int) -> list[tuple[int, int]]:
         """Edge-balanced contiguous vertex ranges (for distributed loaders)."""
@@ -277,16 +289,20 @@ class AsyncRead:
 
     def __init__(self, g: GraphHandle, partitions: list[tuple[int, int]],
                  callback: Callable[[PartitionBuffer], None], *,
-                 n_buffers: int, n_workers: int, raw: bool = False):
+                 n_buffers: int, n_workers: int, raw: bool = False,
+                 tracer=None, trace_root=None):
         self._g = g
         self._callback = callback
         self._raw = raw
-        self._work: "queue.Queue[Optional[tuple[int,int]]]" = queue.Queue()
+        self._tracer = tracer if tracer is not None else PROFILER_TRACER
+        self._trace_root = trace_root
+        self._work: "queue.Queue[tuple[int, tuple[int, int]]]" = \
+            queue.Queue()
         self._pool: "queue.Queue[PartitionBuffer]" = queue.Queue()
         for _ in range(max(1, n_buffers)):
             self._pool.put(PartitionBuffer())
-        for p in partitions:
-            self._work.put(p)
+        for i, p in enumerate(partitions):
+            self._work.put((i, p))
         self._n_left = len(partitions)
         self._done = threading.Event()
         if not partitions:
@@ -307,26 +323,36 @@ class AsyncRead:
             self._errors.append(e)  # a guaranteed atomic publication point
 
     def _producer(self) -> None:
+        with self._tracer.attach(self._trace_root), \
+                pgfuse.reads_traced_by(self._tracer):
+            self._produce()
+
+    def _produce(self) -> None:
+        tracer = self._tracer
         while True:
             try:
-                part = self._work.get_nowait()
+                i, part = self._work.get_nowait()
             except queue.Empty:
                 return
             buf = self._pool.get()  # backpressure: wait for a free buffer
+            t0 = time.perf_counter()
             try:
+                buf.part = i
                 buf.v0, buf.v1 = part
-                if self._raw:
-                    offs, packed, b = self._g.read_partition_raw(*part)
-                    buf.offsets, buf.packed, buf.b = offs, packed, b
-                    buf.neighbors = None
-                else:
-                    offs, nbrs = self._g.read_partition(*part)
-                    buf.offsets, buf.neighbors = offs, nbrs
-                    buf.packed = None
+                with tracer.span("stream.read", tier="storage", part=i):
+                    if self._raw:
+                        offs, packed, b = self._g.read_partition_raw(*part)
+                        buf.offsets, buf.packed, buf.b = offs, packed, b
+                        buf.neighbors = None
+                    else:
+                        offs, nbrs = self._g.read_partition(*part)
+                        buf.offsets, buf.neighbors = offs, nbrs
+                        buf.packed = None
                 buf.error = None
             except BaseException as e:  # surfaced via wait()
                 buf.error = e
                 self._record_error(e)
+            buf.read_s = time.perf_counter() - t0
             try:
                 with self._cb_lock:
                     self._callback(buf)

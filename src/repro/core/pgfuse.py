@@ -61,6 +61,7 @@ entirely; a miss lands here as packed-byte block reads.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -83,6 +84,23 @@ DEFAULT_BLOCK_SIZE = 32 * 2**20  # 32 MiB (paper §III)
 EVICT_LRU = "lru"          # exact recency order — sequential scans
 EVICT_CLOCK = "clock"      # second-chance ref bits — random access
 EVICTION_POLICIES = (EVICT_LRU, EVICT_CLOCK)
+
+# the tracer this thread's reads go to, ahead of the file's and mount's
+_THREAD = threading.local()
+
+
+@contextlib.contextmanager
+def reads_traced_by(tracer):
+    """Make every PG-Fuse read this thread issues inside the block a
+    ``pgfuse.read`` span of ``tracer``, whatever tracer the file or its
+    mount carries.  A stream hands its own tracer to its storage reads
+    this way and leaves the shared mount as it found it."""
+    prev = getattr(_THREAD, "tracer", None)
+    _THREAD.tracer = tracer
+    try:
+        yield
+    finally:
+        _THREAD.tracer = prev
 
 
 @dataclasses.dataclass
@@ -253,13 +271,13 @@ class CachedFile:
         (tests/conftest.py::FaultyStorage wraps ``_read_underlying_range``)
         exercise the same policy a real storage error would.
         """
-        tracer = self.tracer
-        if tracer is None:
-            tracer = (self._fs.tracer if self._fs is not None
-                      else None) or _NULL_TRACER
+        tracer = (getattr(_THREAD, "tracer", None) or self.tracer
+                  or (self._fs.tracer if self._fs is not None else None)
+                  or _NULL_TRACER)
         # tier=storage: under a request this nests inside the engine's
-        # gather span; with no request context (producer threads) the
-        # tracer suppresses it rather than recording an orphan root
+        # gather span, in a load inside stream.read/stream.plan; with no
+        # context at all a recording tracer suppresses it rather than
+        # recording an orphan root
         with tracer.span("pgfuse.read", tier="storage",
                          block=int(b0), blocks=int(n_blocks)) as sp:
             attempt = 0

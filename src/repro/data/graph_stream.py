@@ -31,6 +31,26 @@ Entry point::
         ...
     print(stream.stats)           # per-stage: storage, H2D, decode
 
+Every stage is a span of ``tracer`` (default
+:data:`repro.obs.trace.PROFILER_TRACER`, which only mirrors into a running
+JAX profiler) and a counter of :class:`StreamStats`, timed at the same
+sites (docs/observability.md lists them):
+
+    thread    span            counter          what
+    caller    stream.plan     plan_s           partition_plan + split_plan
+    producer  stream.read     read_s           one partition from storage
+    producer  stream.handoff  handoff_wait_s   raw queue full (staging behind)
+    staging   stream.wait     stage_wait_s     raw queue empty (storage behind)
+    staging   stream.pad      pad_s            pad_packed_for_stream
+    staging   stream.h2d      h2d_s            device_put (its host side)
+    staging   stream.ready    ready_s          decode, slice, until ready
+
+A recording :class:`repro.obs.trace.Tracer` keeps one ``stream.load``
+tree per stream, every stage span in it, each carrying ``part`` (the
+partition's index in the stream's plan).  The stream's own PG-Fuse reads
+(plan, producers, features) are ``pgfuse.read`` spans of the same tracer
+(:func:`repro.core.pgfuse.reads_traced_by`); the mount is left alone.
+
 The iterator is bounded and backpressured end to end: at most
 ``readahead`` partitions sit decoded-or-packed on the host and at most
 ``n_buffers`` shards sit staged on device ahead of the consumer; a slow
@@ -39,6 +59,7 @@ consumer stalls the producers through the read_async buffer pool.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -50,6 +71,7 @@ import numpy as np
 from repro.core import pgfuse, policy
 from repro.core.csr import CSR
 from repro.core.paragrapher import GraphHandle, PartitionBuffer
+from repro.obs.trace import PROFILER_TRACER
 
 
 @dataclasses.dataclass
@@ -92,12 +114,25 @@ class StreamStats:
     edges: int = 0
     decode_mode: str = ""          # "device" | "host" ("mixed" after merge)
     decode_reason: str = ""
-    # storage stage (PG-Fuse deltas; zero when the graph is not mounted)
+    # plan stage (partition_plan over the offsets, on the caller)
+    plan_s: float = 0.0
+    plan_underlying_reads: int = 0  # the graph file's PG-Fuse delta
+    plan_underlying_bytes: int = 0  # across the plan
+    # storage stage (PG-Fuse deltas after the plan; zero when the graph
+    # is not mounted)
     underlying_reads: int = 0
     underlying_bytes: int = 0
     cache_hits: int = 0
     cache_misses: int = 0
     readahead_blocks: int = 0
+    read_s: float = 0.0            # producer seconds in partition reads
+    handoff_wait_s: float = 0.0    # producer seconds waiting on staging
+    # staging stage: decode_s == pad_s + h2d_s + ready_s
+    stage_wait_s: float = 0.0      # staging seconds waiting on storage
+    pad_s: float = 0.0             # padding the packed bytes
+    pad_bytes: int = 0             # bytes the padding added
+    h2d_s: float = 0.0             # device_put calls (their host side)
+    ready_s: float = 0.0           # decode and slice, until ready
     # transfer stage
     bytes_h2d: int = 0             # topology bytes host->device (packed!)
     # decode stage
@@ -124,7 +159,8 @@ class StreamStats:
 
     @property
     def h2d_bytes_per_s(self) -> float:
-        return self.bytes_h2d / self.wall_s if self.wall_s > 0 else 0.0
+        """Topology bytes over the seconds spent in ``device_put``."""
+        return self.bytes_h2d / self.h2d_s if self.h2d_s > 0 else 0.0
 
     @property
     def edges_per_s(self) -> float:
@@ -141,9 +177,10 @@ class StreamStats:
 
     def merge(self, other: "StreamStats") -> "StreamStats":
         """Combine two hosts' stats into the aggregate (returns a new
-        instance).  Counters sum; decode seconds sum (total decode work);
-        wall seconds take the max (hosts stream concurrently); mode/reason
-        collapse to "mixed"/"" when the hosts disagree.
+        instance).  Counters sum; stage seconds sum (total work, decode_s
+        and the per-stage timers alike); wall seconds take the max (hosts
+        stream concurrently); mode/reason collapse to "mixed"/"" when the
+        hosts disagree.
         """
         merged = {f.name: getattr(self, f.name) + getattr(other, f.name)
                   for f in dataclasses.fields(self)
@@ -191,7 +228,7 @@ class GraphStream:
                  decode_plan: Optional[policy.StreamDecodePlan] = None,
                  process_index: int = 0, process_count: int = 1,
                  feature_path=None, label_path=None, shares=None,
-                 align: int = 1):
+                 align: int = 1, tracer=None):
         # jax-facing imports are deferred to the staging stage so the
         # storage layer stays importable without jax
         from repro.kernels.compbin_decode import STREAM_GRANULE_IDS
@@ -205,21 +242,40 @@ class GraphStream:
         self._granule = granule or STREAM_GRANULE_IDS
         self.process_index = process_index
         self.process_count = process_count
+        self._tracer = tracer if tracer is not None else PROFILER_TRACER
+        # the load's root lives off every thread's span stack: the caller
+        # may open and close its own spans around the stream's lifetime
+        self._root = self._tracer.open_root(
+            "stream.load", tier="load", process_index=process_index)
         # Every process derives the SAME global plan from the same file,
         # then streams only its split_plan slice — the cut points agree
         # across hosts with no communication (the plan, the capacity
         # ``shares``, and the block grid ``align`` are the same inputs on
         # every host; shares come from allgathered last-epoch stats, see
         # graph.partition.resplit_from_stats).
-        self.global_plan = graph.partition_plan(
-            self._default_parts(n_parts, mesh, process_count))
-        self.plan = split_plan(self.global_plan, process_count,
-                               shares=shares, align=align)[process_index]
+        n_global = self._default_parts(n_parts, mesh, process_count)
+        pg_plan = graph.pgfuse_file_stats()
+        t_plan = time.perf_counter()
+        with self._traced(), \
+                self._tracer.span("stream.plan", tier="storage",
+                                  parts=n_global):
+            self.global_plan = graph.partition_plan(n_global)
+            self.plan = split_plan(self.global_plan, process_count,
+                                   shares=shares,
+                                   align=align)[process_index]
+        plan_s = time.perf_counter() - t_plan
         self.host_range = host_vertex_range(self.plan)
         self.decode_plan = decode_plan or policy.choose_stream_decode(
             graph.format, graph.bytes_per_id)
         self.stats = StreamStats(decode_mode=self.decode_plan.mode,
-                                 decode_reason=self.decode_plan.reason)
+                                 decode_reason=self.decode_plan.reason,
+                                 plan_s=plan_s)
+        if pg_plan is not None:
+            pg = graph.pgfuse_file_stats()
+            self.stats.plan_underlying_reads = \
+                pg.underlying_reads - pg_plan.underlying_reads
+            self.stats.plan_underlying_bytes = \
+                pg.underlying_bytes - pg_plan.underlying_bytes
         # stream_features stage: the node-feature store rides the same
         # PG-Fuse mount as the topology (shared memory budget + readahead
         # policy, its own per-file block cache and stats)
@@ -259,7 +315,8 @@ class GraphStream:
         self._rawq: "queue.Queue" = queue.Queue(maxsize=max(1, readahead))
         self._async = graph.read_async(
             self.plan, self._on_partition, n_buffers=max(2, n_buffers),
-            n_workers=max(1, n_workers), raw=self.decode_plan.device)
+            n_workers=max(1, n_workers), raw=self.decode_plan.device,
+            tracer=self._tracer, trace_root=self._root)
 
         # stage 2: H2D staging + device decode, on a prefetch thread
         from repro.data.prefetch import PrefetchIterator
@@ -281,37 +338,79 @@ class GraphStream:
 
     # -- stage 1: the read_async consumer callback -------------------------
     def _on_partition(self, buf: PartitionBuffer) -> None:
+        # runs on a producer thread, under read_async's callback lock
+        self.stats.read_s += buf.read_s
         if self._drop.is_set():
             return
         if buf.error is not None:
             item = ("err", buf.error)
         elif buf.packed is not None:
-            item = ("raw", (buf.v0, buf.v1, buf.offsets, buf.packed, buf.b))
+            item = ("raw", (buf.part, buf.v0, buf.v1, buf.offsets,
+                            buf.packed, buf.b))
         else:
-            item = ("host", (buf.v0, buf.v1, buf.offsets, buf.neighbors))
-        while not self._drop.is_set():
-            try:
-                self._rawq.put(item, timeout=0.05)
-                return
-            except queue.Full:
-                continue
+            item = ("host", (buf.part, buf.v0, buf.v1, buf.offsets,
+                             buf.neighbors))
+        try:
+            self._rawq.put_nowait(item)
+            return
+        except queue.Full:
+            pass
+        t0 = time.perf_counter()
+        with self._tracer.span("stream.handoff", part=buf.part):
+            while not self._drop.is_set():
+                try:
+                    self._rawq.put(item, timeout=0.05)
+                    break
+                except queue.Full:
+                    continue
+        self.stats.handoff_wait_s += time.perf_counter() - t0
+
+    def _next_raw(self):
+        """The next item of the raw queue on the staging thread, None
+        once the stream is dropped; time spent waiting is storage's."""
+        try:
+            return self._rawq.get_nowait()
+        except queue.Empty:
+            pass
+        t0 = time.perf_counter()
+        try:
+            with self._tracer.attach(self._root), \
+                    self._tracer.span("stream.wait"):
+                while True:
+                    try:
+                        return self._rawq.get(timeout=0.05)
+                    except queue.Empty:
+                        if self._drop.is_set():
+                            return None
+        finally:
+            self.stats.stage_wait_s += time.perf_counter() - t0
 
     def _raw_iter(self) -> Iterator:
         received = 0
         while received < self._n_expected:
-            try:
-                kind, payload = self._rawq.get(timeout=0.05)
-            except queue.Empty:
-                if self._drop.is_set():
-                    return
-                continue
+            item = self._next_raw()
+            if item is None:
+                return
             received += 1
+            kind, payload = item
             if kind == "err":
                 raise payload
-            yield (kind, payload)
+            yield item
 
     # -- stage 2: staging + decode ----------------------------------------
+    def _traced(self):
+        """Join this thread to the load's span tree, and hand the
+        stream's tracer to the PG-Fuse reads it makes meanwhile."""
+        stack = contextlib.ExitStack()
+        stack.enter_context(self._tracer.attach(self._root))
+        stack.enter_context(pgfuse.reads_traced_by(self._tracer))
+        return stack
+
     def _stage(self, item) -> StreamedShard:
+        with self._traced():
+            return self._stage_partition(item)
+
+    def _stage_partition(self, item) -> StreamedShard:
         import jax
         import jax.numpy as jnp
 
@@ -319,31 +418,53 @@ class GraphStream:
         from repro.kernels.compbin_decode import (compbin_decode,
                                                   pad_packed_for_stream)
 
+        span = self._tracer.span
         kind, payload = item
-        t0 = time.perf_counter()
         place = lambda n_ids: stream_shard_placement(
             self._mesh, n_ids, process_index=self.process_index,
             process_count=self.process_count)
+        # three back-to-back timers: decode_s == pad_s + h2d_s + ready_s
+        t0 = t_pad = time.perf_counter()
         if kind == "raw":
-            v0, v1, offs, packed, b = payload
-            padded, n = pad_packed_for_stream(packed, b, granule=self._granule)
-            nbr_shard, off_shard = place(len(padded) // b)
-            # H2D: packed bytes only, straight to their placement; a
-            # stream split over "data" decodes on each device's own slice
-            dev_packed = jax.device_put(padded, nbr_shard)
-            decoded = compbin_decode(dev_packed, b)   # eq. (1) on device
-            neighbors = decoded[:n]
+            part, v0, v1, offs, packed, b = payload
+            with span("stream.pad", tier="stream", part=part):
+                padded, n = pad_packed_for_stream(packed, b,
+                                                  granule=self._granule)
+            self.stats.pad_bytes += padded.nbytes - packed.nbytes
+            t_pad = time.perf_counter()
             h2d = padded.nbytes
+            with span("stream.h2d", tier="h2d", part=part,
+                      bytes=h2d + offs.nbytes):
+                nbr_shard, off_shard = place(len(padded) // b)
+                # H2D: packed bytes only, straight to their placement; a
+                # stream split over "data" decodes on each device's own
+                # slice
+                dev_packed = jax.device_put(padded, nbr_shard)
+                offsets = self._put_offsets(offs, off_shard)
+            t_h2d = time.perf_counter()
+            with span("stream.ready", tier="decode", part=part):
+                decoded = compbin_decode(dev_packed, b)   # eq. (1) on device
+                neighbors = decoded[:n]
+                # charge decode to this stage, not to the consumer's
+                # first use
+                neighbors.block_until_ready()
+                offsets.block_until_ready()
         else:  # host-decoded partition (WebGraph, or CompBin with b > 4)
-            v0, v1, offs, nbrs = payload
+            part, v0, v1, offs, nbrs = payload
             n = len(nbrs)
             dtype = np.int32 if self._graph.n_vertices <= np.iinfo(np.int32).max \
                 else np.int64
-            host_nbrs = np.ascontiguousarray(nbrs, dtype=dtype)
-            nbr_shard, off_shard = place(n)
-            neighbors = jnp.asarray(host_nbrs)
-            if nbr_shard is not None:
-                neighbors = jax.device_put(neighbors, nbr_shard)
+            with span("stream.h2d", tier="h2d", part=part):
+                host_nbrs = np.ascontiguousarray(nbrs, dtype=dtype)
+                nbr_shard, off_shard = place(n)
+                neighbors = jnp.asarray(host_nbrs)
+                if nbr_shard is not None:
+                    neighbors = jax.device_put(neighbors, nbr_shard)
+                offsets = self._put_offsets(offs, off_shard)
+            t_h2d = time.perf_counter()
+            with span("stream.ready", tier="decode", part=part):
+                neighbors.block_until_ready()
+                offsets.block_until_ready()
             h2d = host_nbrs.nbytes
             if self._graph.bytes_per_id > 0:
                 # fixed-width packed bytes this partition decoded on the
@@ -353,12 +474,11 @@ class GraphStream:
                 self.stats.host_decode_bytes += n * self._graph.bytes_per_id
             else:
                 self.stats.host_decode_bytes += host_nbrs.nbytes
-        offsets = jnp.asarray(offs)
-        if off_shard is not None:
-            offsets = jax.device_put(offsets, off_shard)
-        neighbors.block_until_ready()   # charge decode to this stage, not
-        offsets.block_until_ready()     # to the consumer's first use
-        self.stats.decode_s += time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self.stats.pad_s += t_pad - t0
+        self.stats.h2d_s += t_h2d - t_pad
+        self.stats.ready_s += t1 - t_h2d
+        self.stats.decode_s += t1 - t0
         self.stats.bytes_h2d += h2d + offs.nbytes
         # the feature stage runs OUTSIDE the decode timer: its cost is
         # feature_read_s, not decode_s
@@ -366,6 +486,16 @@ class GraphStream:
         y = self._stream_labels(v0, v1, off_shard)
         return StreamedShard(v0=v0, v1=v1, offsets=offsets,
                              neighbors=neighbors, n_edges=n, x=x, y=y)
+
+    @staticmethod
+    def _put_offsets(offs: np.ndarray, placement):
+        import jax
+        import jax.numpy as jnp
+
+        offsets = jnp.asarray(offs)
+        if placement is not None:
+            offsets = jax.device_put(offsets, placement)
+        return offsets
 
     def _stream_features(self, v0: int, v1: int, placement):
         """The stream_features stage: feature rows [v0, v1) from the
@@ -427,6 +557,10 @@ class GraphStream:
     def _finalize(self) -> None:
         if self.stats.wall_s == 0.0:
             self.stats.wall_s = time.perf_counter() - self._t0
+            if self._root is not None:
+                self._root.attrs.update(partitions=self.stats.partitions,
+                                        edges=self.stats.edges)
+            self._tracer.close_root(self._root)
         pg = self._graph.pgfuse_file_stats()
         if pg is not None:
             self.stats.underlying_reads = pg.underlying_reads - self._pg0.underlying_reads
@@ -473,7 +607,7 @@ def stream_partitions(graph: GraphHandle, mesh=None, *,
                       decode_plan: Optional[policy.StreamDecodePlan] = None,
                       process_index: int = 0, process_count: int = 1,
                       feature_path=None, label_path=None, shares=None,
-                      align: int = 1) -> GraphStream:
+                      align: int = 1, tracer=None) -> GraphStream:
     """Stream an open graph to the device(s) partition by partition.
 
     Parameters mirror the pipeline's three bounds: ``readahead`` partitions
@@ -502,12 +636,19 @@ def stream_partitions(graph: GraphHandle, mesh=None, *,
     ``align`` snaps the inter-host cuts to a block grid so private caches
     never double-fetch a boundary feature block.  ``data/multihost.py``
     simulates this in one process for tests and single-node runs.
+
+    ``tracer`` (default :data:`repro.obs.trace.PROFILER_TRACER`) takes a
+    span for every stage of every partition and every PG-Fuse read the
+    stream makes (module docstring); a recording
+    :class:`repro.obs.trace.Tracer` keeps them as one ``stream.load``
+    tree.
     """
     return GraphStream(graph, mesh, n_buffers=n_buffers, readahead=readahead,
                        n_parts=n_parts, n_workers=n_workers, granule=granule,
                        decode_plan=decode_plan, process_index=process_index,
                        process_count=process_count, feature_path=feature_path,
-                       label_path=label_path, shares=shares, align=align)
+                       label_path=label_path, shares=shares, align=align,
+                       tracer=tracer)
 
 
 def assemble_csr(shards: list[StreamedShard]) -> CSR:

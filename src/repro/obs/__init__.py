@@ -4,7 +4,8 @@
 ``docs/observability.md``):
 
 * :mod:`repro.obs.trace` — per-request span tracing with deterministic
-  ids, an injectable (virtual) clock, and zero-cost no-op default;
+  ids, an injectable (virtual) clock, a zero-cost no-op default, and a
+  sink in the JAX profiler;
 * :mod:`repro.obs.metrics` — the bounded latency histogram every stats
   class retains, plus the registry / namespace / drift check;
 * :mod:`repro.obs.report` — per-tier time attribution and the
@@ -35,6 +36,8 @@ from .trace import (
     NAMED_TIERS,
     NULL_TRACER,
     NullTracer,
+    PROFILER_TRACER,
+    ProfilerTracer,
     ROOT_TIERS,
     Span,
     SpanEvent,
@@ -47,6 +50,7 @@ __all__ = [
     "STATS_SOURCES", "flatten_numeric", "metrics_drift",
     "attribution", "event_counts", "render_report", "tier_times",
     "verify_span_tree", "window_close_counts",
-    "NAMED_TIERS", "NULL_TRACER", "NullTracer", "ROOT_TIERS",
+    "NAMED_TIERS", "NULL_TRACER", "NullTracer", "PROFILER_TRACER",
+    "ProfilerTracer", "ROOT_TIERS",
     "Span", "SpanEvent", "TraceContext", "Tracer",
 ]

@@ -188,8 +188,11 @@ NAMESPACE = {
     ),
     "stream": (
         "partitions", "vertices", "edges", "decode_mode",
-        "decode_reason", "underlying_reads", "underlying_bytes",
-        "cache_hits", "cache_misses", "readahead_blocks", "bytes_h2d",
+        "decode_reason", "plan_s", "plan_underlying_reads",
+        "plan_underlying_bytes", "underlying_reads", "underlying_bytes",
+        "cache_hits", "cache_misses", "readahead_blocks", "read_s",
+        "handoff_wait_s", "stage_wait_s", "pad_s", "pad_bytes", "h2d_s",
+        "ready_s", "bytes_h2d",
         "host_decode_bytes", "decode_s", "feature_rows",
         "feature_bytes", "feature_bytes_h2d", "feature_read_s",
         "feature_cache_hits", "feature_cache_misses", "label_rows",
@@ -216,7 +219,7 @@ RATIO_SPECS = {
     "pgfuse.hit_rate": (("pgfuse.cache_hits",),
                         ("pgfuse.cache_hits", "pgfuse.cache_misses")),
     "stream.decode_edges_per_s": (("stream.edges",), ("stream.decode_s",)),
-    "stream.h2d_bytes_per_s": (("stream.bytes_h2d",), ("stream.wall_s",)),
+    "stream.h2d_bytes_per_s": (("stream.bytes_h2d",), ("stream.h2d_s",)),
     "stream.edges_per_s": (("stream.edges",), ("stream.wall_s",)),
     "stream.feature_bytes_per_s": (("stream.feature_bytes",),
                                    ("stream.wall_s",)),

@@ -1,4 +1,5 @@
-"""Span tracing for the serving path — deterministic, injectable-clock.
+"""Span tracing for the serving and loading paths — deterministic,
+injectable-clock, with a sink in the JAX profiler.
 
 One traversal request crosses five layers (router -> engine micro-batch
 -> hot-set tier -> PG-Fuse -> decode), each with its own ``*Stats``
@@ -14,7 +15,13 @@ attributing the request's (virtual-clock) time to tiers:
 ``storage``  PG-Fuse underlying reads (cache misses only — hits never
              touch storage and correctly attribute nothing here)
 ``decode``   eq. (1), host or device
-``h2d``      packed-byte transfer accounting on the device path
+``h2d``      a host-to-device transfer (``stream.h2d``)
+
+The whole-graph load (``repro.data.graph_stream``) opens one ``load``
+root per stream (``stream.load``); its producer and staging threads
+join that tree through :meth:`Tracer.attach`, and its per-partition
+stage spans carry the tiers ``storage``, ``stream``, ``h2d``,
+``decode`` and ``other``.
 
 Design constraints, all load-bearing:
 
@@ -23,8 +30,8 @@ Design constraints, all load-bearing:
   ``ShardedQueryService(tracer=...)``, ``TraversalService(tracer=...)``,
   ``PGFuseFS.tracer``).  Two services with two tracers never share
   state;
-* **zero-cost when disabled** — :data:`NULL_TRACER` (the default
-  everywhere) returns one shared no-op handle; the serving path adds
+* **zero-cost when disabled** — :data:`NULL_TRACER` (the serving
+  path's default) returns one shared no-op handle; the serving path adds
   only an attribute load + a no-op context manager per span site, and
   the bench lane's tracked gates prove no regression;
 * **deterministic** — span ids come from a seeded counter, timestamps
@@ -36,6 +43,15 @@ Design constraints, all load-bearing:
   (``dropped_traces`` counts the overflow), and sampling keeps only
   every ``sample_every``-th root, suppressing the whole subtree of an
   unsampled request (children of a suppressed span never become roots).
+
+**Profiler sink.**  :data:`PROFILER_TRACER` enters a
+``jax.profiler.TraceAnnotation`` for every span and does nothing else:
+it keeps nothing, samples nothing, has no orphan rule, and is the
+loading path's default.  A profiler started around the program then
+sees the load's spans on the device trace's clock, on the thread that
+ran them.  jax is imported lazily, and only once something else has
+imported it (no profiler can run before), so the storage layer imports
+without it.
 
 Span **events** mark point occurrences inside a span: PG-Fuse transient
 retries (``"retry"``), replica failovers (``"reroute"``), admission
@@ -50,6 +66,7 @@ differential fuzzers assert.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Callable, List, Optional, Tuple
@@ -62,7 +79,35 @@ NAMED_TIERS = ("route", "gather", "storage", "decode", "h2d")
 #: tiers — e.g. a storage read issued by a background producer thread
 #: with no request context — are suppressed rather than recorded as
 #: meaningless single-span traces.
-ROOT_TIERS = ("request", "route", "gather")
+ROOT_TIERS = ("request", "route", "gather", "load")
+
+#: ``jax.profiler.TraceAnnotation``, bound on first use once jax is loaded
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    """The profiler's annotation class, or None while jax is not loaded
+    (nothing can be profiling then, and the storage layer must import
+    and run without jax)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def _annotate(name: str, attrs: dict):
+    """An entered profiler annotation for one span (None without jax).
+    Only scalar attrs pass: the profiler shows them as the event's stats."""
+    cls = _annotation_cls()
+    if cls is None:
+        return None
+    ann = cls(name, **{k: v for k, v in attrs.items()
+                       if isinstance(v, (bool, int, float, str))})
+    ann.__enter__()
+    return ann
 
 
 class SpanEvent:
@@ -234,6 +279,12 @@ class NullTracer:
     def attach(self, span) -> _NullHandle:
         return _NULL_HANDLE
 
+    def open_root(self, name: str, tier: str = "other", **attrs) -> None:
+        return None
+
+    def close_root(self, span) -> None:
+        pass
+
     @property
     def current(self) -> None:
         return None
@@ -242,8 +293,45 @@ class NullTracer:
         return []
 
 
-#: the module-wide disabled tracer every component defaults to
+#: the module-wide disabled tracer the serving path defaults to
 NULL_TRACER = NullTracer()
+
+
+class _ProfilerHandle:
+    """A span of :data:`PROFILER_TRACER`: one profiler annotation."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self) -> "_ProfilerHandle":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(None, None, None)
+        return False
+
+    def event(self, name: str, **attrs) -> None:
+        pass
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+class ProfilerTracer(NullTracer):
+    """Spans that only mirror into the JAX profiler: nothing is kept in
+    memory, and there is no sampling and no orphan rule, so a span on a
+    producer thread shows like any other.  With no profiler running each
+    span costs one annotation that records nothing (about a microsecond)."""
+
+    def span(self, name: str, tier: str = "other", **attrs):
+        ann = _annotate(name, attrs)
+        return _NULL_HANDLE if ann is None else _ProfilerHandle(ann)
+
+
+#: the loading path's default tracer
+PROFILER_TRACER = ProfilerTracer()
 
 
 class Tracer:
@@ -306,25 +394,32 @@ class Tracer:
             ctx.suppress += 1
             return self._suppressed
         parent = ctx.stack[-1] if ctx.stack else None
-        if parent is None:
-            if tier not in self.root_tiers:
-                ctx.suppress += 1
-                return self._suppressed
-            with self._lock:
-                nth = self._roots_seen
-                self._roots_seen += 1
-            if nth % self.sample_every:
-                ctx.suppress += 1
-                return self._suppressed
-        with self._lock:
-            sid = self._next_id
-            self._next_id += 1
-        sp = Span(sid, parent.span_id if parent is not None else None,
-                  name, tier, self._clock(), attrs)
+        if parent is None and not self._sample_root(tier):
+            ctx.suppress += 1
+            return self._suppressed
+        sp = self._new_span(parent, name, tier, attrs)
         if parent is not None:
             parent.children.append(sp)
         ctx.stack.append(sp)
         return _SpanHandle(self, sp)
+
+    def _sample_root(self, tier: str) -> bool:
+        """Whether a root of ``tier`` is recorded: a root tier, and the
+        sampler's pick."""
+        if tier not in self.root_tiers:
+            return False
+        with self._lock:
+            nth = self._roots_seen
+            self._roots_seen += 1
+        return nth % self.sample_every == 0
+
+    def _new_span(self, parent: Optional[Span], name: str, tier: str,
+                  attrs: dict) -> Span:
+        with self._lock:
+            sid = self._next_id
+            self._next_id += 1
+        return Span(sid, parent.span_id if parent is not None else None,
+                    name, tier, self._clock(), attrs)
 
     def _finish(self, sp: Span) -> None:
         sp.t1 = self._clock()
@@ -332,11 +427,33 @@ class Tracer:
         assert stack and stack[-1] is sp, "span exited out of order"
         stack.pop()
         if sp.parent_id is None:
-            with self._lock:
-                if len(self.traces) < self.max_traces:
-                    self.traces.append(sp)
-                else:
-                    self.dropped_traces += 1
+            self._retain(sp)
+
+    def _retain(self, root: Span) -> None:
+        with self._lock:
+            if len(self.traces) < self.max_traces:
+                self.traces.append(root)
+            else:
+                self.dropped_traces += 1
+
+    def open_root(self, name: str, tier: str = "other",
+                  **attrs) -> Optional[Span]:
+        """Open a root span OFF every thread's span stack, for work that
+        outlives the caller's own ``with`` blocks (a stream, open from
+        its construction to its last shard).  Threads join it through
+        :meth:`attach`; :meth:`close_root` ends and retains it.  Returns
+        None when the root is not recorded (not a root tier, or not
+        sampled); ``attach(None)`` then suppresses the subtree."""
+        if not self._sample_root(tier):
+            return None
+        return self._new_span(None, name, tier, attrs)
+
+    def close_root(self, span: Optional[Span]) -> None:
+        """End a root from :meth:`open_root` (no-op for None)."""
+        if span is None:
+            return
+        span.t1 = self._clock()
+        self._retain(span)
 
     def event(self, name: str, **attrs) -> None:
         """Attach an event to the calling thread's current span (dropped
@@ -353,6 +470,9 @@ class Tracer:
 
             with tracer.attach(request_span):
                 ...   # spans opened here nest under request_span
+
+        ``attach(None)`` (an unrecorded root from :meth:`open_root`)
+        suppresses every span opened inside it.
         """
         return _AttachHandle(self, span)
 
@@ -366,20 +486,28 @@ class Tracer:
 
 class _AttachHandle:
     """Context manager pushing an existing span as this thread's
-    current parent (see :meth:`Tracer.attach`)."""
+    current parent (see :meth:`Tracer.attach`); for None, suppressing
+    the subtree instead."""
 
     __slots__ = ("_tracer", "_span")
 
-    def __init__(self, tracer: Tracer, span: Span):
+    def __init__(self, tracer: Tracer, span: Optional[Span]):
         self._tracer = tracer
         self._span = span
 
-    def __enter__(self) -> Span:
-        self._tracer._ctx().stack.append(self._span)
+    def __enter__(self) -> Optional[Span]:
+        ctx = self._tracer._ctx()
+        if self._span is None:
+            ctx.suppress += 1
+        else:
+            ctx.stack.append(self._span)
         return self._span
 
     def __exit__(self, *exc) -> bool:
-        stack = self._tracer._ctx().stack
-        assert stack and stack[-1] is self._span
-        stack.pop()
+        ctx = self._tracer._ctx()
+        if self._span is None:
+            ctx.suppress -= 1
+            return False
+        assert ctx.stack and ctx.stack[-1] is self._span
+        ctx.stack.pop()
         return False

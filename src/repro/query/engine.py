@@ -571,18 +571,15 @@ class NeighborQueryEngine:
                     if len(spans) else 0
                 plan = self._decode_plan(n_edges)
                 if plan.device:
+                    # the transfer is inside the device decode: its
+                    # bytes ride on the decode span, and the h2d tier
+                    # names only timed transfers (stream.h2d)
                     with self._tracer.span("query.decode", tier="decode",
                                            mode="device",
                                            edges=n_edges) as dsp:
                         decoded_cold, bytes_h2d = \
                             self._decode_device(packed)
-                        # zero-width marker carrying the shipped bytes:
-                        # H2D cost is folded into the device decode
-                        # under the virtual clock, but the tier stays
-                        # visible in the attribution
-                        with self._tracer.span("query.h2d",
-                                               tier="h2d") as hsp:
-                            hsp.set(bytes=int(bytes_h2d))
+                        dsp.set(bytes_h2d=int(bytes_h2d))
                 else:
                     with self._tracer.span("query.decode", tier="decode",
                                            mode="host", edges=n_edges):

@@ -103,12 +103,18 @@ def test_stream_surfaces_storage_error_not_hang(graph_file, faulty_storage):
     path, csr = graph_file
     with paragrapher.open_graph(path, use_pgfuse=True,
                                 pgfuse_block_size=BLOCK) as g:
-        stream = stream_partitions(g, None, n_parts=4, n_workers=1)
+        # the offsets sit in PG-Fuse before the fault is armed, so the
+        # stream's plan reads nothing and the first call storage sees is
+        # a producer's (arming it after the stream starts raced them)
+        g.partition_plan(4)
         faulty_storage.fail_at[1] = OSError(errno.EIO, "flaky OST")
         faulty_storage.install_graph(g)
+        stream = stream_partitions(g, None, n_parts=4, n_workers=1)
+        assert stream.stats.plan_underlying_reads == 0
         with pytest.raises(OSError):
             with stream:
                 list(stream)
+        assert faulty_storage.calls[0][3] == -1
 
 
 def test_stream_recovers_after_transient_error(graph_file, faulty_storage):

@@ -151,9 +151,11 @@ def test_stream_stats_zero_duration_guards():
     assert s.edges_per_s == 0.0
     d = s.as_dict()
     assert d["decode_edges_per_s"] == 0.0 and d["h2d_bytes_per_s"] == 0.0
-    live = StreamStats(edges=1000, decode_s=0.5, wall_s=2.0, bytes_h2d=4096)
+    live = StreamStats(edges=1000, decode_s=0.5, wall_s=2.0, bytes_h2d=4096,
+                       h2d_s=0.25)
     assert live.decode_edges_per_s == 2000.0
-    assert live.h2d_bytes_per_s == 2048.0
+    # the link rate: bytes over the seconds spent transferring them
+    assert live.h2d_bytes_per_s == 16384.0
 
 
 def test_stream_stats_merge_associative_and_commutative_totals():
