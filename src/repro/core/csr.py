@@ -7,7 +7,9 @@ the index of the first neighbor of ``v`` in ``neighbors``.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
+from typing import Sequence
 
 import numpy as np
 
@@ -89,3 +91,27 @@ def csr_from_edges(src: np.ndarray, dst: np.ndarray, n_vertices: int, *,
     np.cumsum(counts, out=offsets[1:])
     dtype = np.int32 if n_vertices <= np.iinfo(np.int32).max else np.int64
     return CSR(offsets=offsets, neighbors=dst_s.astype(dtype))
+
+
+def edge_balanced_ranges(offsets: Sequence[int], n_parts: int
+                         ) -> list[tuple[int, int]]:
+    """Contiguous vertex ranges with about ``|E| / n_parts`` edges each.
+
+    Cut ``i`` is the first ``v`` with ``offsets[v] >= total * (i + 1) //
+    n_parts`` (``total = offsets[-1]``), clipped to ``[1, |V|]``; repeats
+    merge and the last range closes at ``|V|``.  ``offsets`` is any
+    ascending sequence of ``|V| + 1`` integers: an array, or a view that
+    reads one entry per index from a file.  The cuts are found by
+    bisection, each starting from the previous cut, so a plan reads about
+    ``n_parts * log2(|V|)`` entries.
+    """
+    n_vertices = len(offsets) - 1
+    total = int(offsets[n_vertices])
+    cuts, lo = set(), 0
+    for i in range(n_parts):
+        lo = bisect.bisect_left(offsets, (total * (i + 1)) // n_parts, lo)
+        cuts.add(min(max(lo, 1), n_vertices))
+    bounds = [0] + sorted(cuts)
+    if bounds[-1] != n_vertices:
+        bounds.append(n_vertices)
+    return list(zip(bounds[:-1], bounds[1:]))

@@ -21,6 +21,7 @@ the JVM.  Formats: CompBin (paper §IV) and the WebGraph-style codec
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import queue
@@ -31,7 +32,7 @@ from typing import Callable, Iterable, Optional, Sequence, Union
 import numpy as np
 
 from repro.core import codec, pgfuse, webgraph
-from repro.core.csr import CSR
+from repro.core.csr import CSR, edge_balanced_ranges
 from repro.obs.trace import PROFILER_TRACER
 
 FORMAT_COMPBIN = "compbin"
@@ -63,6 +64,20 @@ class PartitionBuffer:
     b: int = 0                              # bytes/ID of ``packed``
     read_s: float = 0.0                     # seconds the read took
     error: Optional[BaseException] = None
+
+
+class _OffsetsInPlace:
+    """``offsets[v]`` of a direct-addressing reader as a sequence, one
+    entry read per index: what bisection needs, without the array."""
+
+    def __init__(self, rdr):
+        self._rdr = rdr
+
+    def __len__(self) -> int:
+        return self._rdr.n_vertices + 1
+
+    def __getitem__(self, v: int) -> int:
+        return int(self._rdr.offsets(v, v)[0])
 
 
 class GraphHandle:
@@ -226,23 +241,28 @@ class GraphHandle:
                          tracer=tracer, trace_root=trace_root)
 
     def partition_plan(self, n_parts: int) -> list[tuple[int, int]]:
-        """Edge-balanced contiguous vertex ranges (for distributed loaders)."""
+        """Edge-balanced contiguous vertex ranges (for distributed loaders).
+
+        Direct-addressing codecs are bisected in place: the plan reads
+        only the offsets it probes, not the whole array.  On a PG-Fuse
+        mount the offsets' blocks are pinned first, in file order, so
+        storage sees the requests a whole-array read makes and no probe
+        fetches a block again; where the mount's caps cannot hold them,
+        the whole array is read as before.  WebGraph reads its bit
+        offsets whole (its decoder needs them anyway)."""
         rdr = self._reader()
         try:
-            if hasattr(rdr, "offsets"):
-                offs = rdr.offsets()
-            else:
-                offs = rdr.bit_offsets()  # bit offsets ~ edge mass proxy
+            if not hasattr(rdr, "offsets"):  # bit offsets ~ edge mass
+                return edge_balanced_ranges(rdr.bit_offsets(), n_parts)
+            pin = contextlib.nullcontext(True)  # nothing to pin unmounted
+            if self._fs is not None:
+                pin = self._fs.mount(self.path).pinned(
+                    *rdr.header.offsets_span(0, self.n_vertices - 1))
+            with pin as in_place:
+                offs = _OffsetsInPlace(rdr) if in_place else rdr.offsets()
+                return edge_balanced_ranges(offs, n_parts)
         finally:
             rdr.close()
-        total = int(offs[-1])
-        targets = [(total * (i + 1)) // n_parts for i in range(n_parts)]
-        cuts = np.searchsorted(offs, targets, side="left")
-        cuts = np.clip(cuts, 1, self.n_vertices)
-        bounds = [0] + sorted(set(int(c) for c in cuts))
-        if bounds[-1] != self.n_vertices:
-            bounds.append(self.n_vertices)
-        return [(bounds[i], bounds[i + 1]) for i in range(len(bounds) - 1)]
 
     # -- stats / lifecycle -----------------------------------------------------
     @property

@@ -497,6 +497,41 @@ class CachedFile:
             self._fs._maybe_evict()
         return loaded
 
+    @contextlib.contextmanager
+    def pinned(self, offset: int, size: int):
+        """Hold every block overlapping [offset, offset+size) resident for
+        the ``with`` block, and yield True.
+
+        Blocks are acquired in ascending order, so storage sees exactly
+        the requests a :meth:`pread` of the span makes (readahead
+        included), but no bytes are assembled.  A pinned block cannot be
+        evicted, so small reads inside the span cost no further request,
+        in whatever order they come.  Where a resident cap (the file's,
+        its share's or the mount's) has no room for the span and one
+        readahead run, nothing is pinned and the block yields False.
+        """
+        offset = max(0, offset)
+        end = min(offset + size, self.size)
+        blocks = (range(offset // self.block_size,
+                        (end - 1) // self.block_size + 1)
+                  if end > offset else range(0))
+        room = (len(blocks) + self.readahead) * self.block_size
+        caps = (self.max_resident_bytes,
+                self.share.max_resident_bytes if self.share else None,
+                self._fs.max_resident_bytes if self._fs else None)
+        if any(cap is not None and cap < room for cap in caps):
+            yield False
+            return
+        held = []
+        try:
+            for b in blocks:
+                self.acquire_block(b)
+                held.append(b)
+            yield True
+        finally:
+            for b in held:
+                self.release_block(b)
+
     # -- eviction (revocation by last-access time) -------------------------
     def try_revoke(self, b: int) -> int:
         """Attempt 0 -> -3 -> free -> -1.  Returns bytes freed (0 if busy)."""

@@ -36,14 +36,16 @@ Every stage is a span of ``tracer`` (default
 JAX profiler) and a counter of :class:`StreamStats`, timed at the same
 sites (docs/observability.md lists them):
 
-    thread    span            counter          what
-    caller    stream.plan     plan_s           partition_plan + split_plan
-    producer  stream.read     read_s           one partition from storage
-    producer  stream.handoff  handoff_wait_s   raw queue full (staging behind)
-    staging   stream.wait     stage_wait_s     raw queue empty (storage behind)
-    staging   stream.pad      pad_s            pad_packed_for_stream
-    staging   stream.h2d      h2d_s            device_put (its host side)
-    staging   stream.ready    ready_s          decode, slice, until ready
+    thread    span            counter            what
+    caller    stream.plan     plan_s             partition_plan + split_plan
+    caller    stream.plan     plan_bytes_served  the offsets the plan probes
+                                                 (it bisects them in place)
+    producer  stream.read     read_s             one partition from storage
+    producer  stream.handoff  handoff_wait_s     raw queue full (staging behind)
+    staging   stream.wait     stage_wait_s       raw queue empty (storage behind)
+    staging   stream.pad      pad_s              pad_packed_for_stream
+    staging   stream.h2d      h2d_s              device_put (its host side)
+    staging   stream.ready    ready_s            decode, slice, until ready
 
 A recording :class:`repro.obs.trace.Tracer` keeps one ``stream.load``
 tree per stream, every stage span in it, each carrying ``part`` (the
@@ -114,10 +116,12 @@ class StreamStats:
     edges: int = 0
     decode_mode: str = ""          # "device" | "host" ("mixed" after merge)
     decode_reason: str = ""
-    # plan stage (partition_plan over the offsets, on the caller)
+    # plan stage (partition_plan over the offsets, on the caller), and
+    # the graph file's PG-Fuse deltas across it
     plan_s: float = 0.0
-    plan_underlying_reads: int = 0  # the graph file's PG-Fuse delta
-    plan_underlying_bytes: int = 0  # across the plan
+    plan_underlying_reads: int = 0  # calls into storage
+    plan_underlying_bytes: int = 0  # bytes fetched from storage
+    plan_bytes_served: int = 0      # bytes the plan read (offsets probed)
     # storage stage (PG-Fuse deltas after the plan; zero when the graph
     # is not mounted)
     underlying_reads: int = 0
@@ -276,6 +280,8 @@ class GraphStream:
                 pg.underlying_reads - pg_plan.underlying_reads
             self.stats.plan_underlying_bytes = \
                 pg.underlying_bytes - pg_plan.underlying_bytes
+            self.stats.plan_bytes_served = \
+                pg.bytes_served - pg_plan.bytes_served
         # stream_features stage: the node-feature store rides the same
         # PG-Fuse mount as the topology (shared memory budget + readahead
         # policy, its own per-file block cache and stats)

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.csr import CSR
+from repro.core.csr import CSR, edge_balanced_ranges
 
 
 def edge_balanced_partition(csr: CSR, n_shards: int, *, pad_value: int = -1
@@ -34,15 +34,8 @@ def edge_balanced_partition(csr: CSR, n_shards: int, *, pad_value: int = -1
 
 def vertex_range_partition(csr: CSR, n_parts: int) -> list[tuple[int, int]]:
     """Contiguous vertex ranges with approximately equal edge counts
-    (mirrors GraphHandle.partition_plan but for in-memory CSR)."""
-    total = csr.n_edges
-    targets = [(total * (i + 1)) // n_parts for i in range(n_parts)]
-    cuts = np.searchsorted(csr.offsets, targets, side="left")
-    cuts = np.clip(cuts, 1, csr.n_vertices)
-    bounds = [0] + sorted(set(int(c) for c in cuts))
-    if bounds[-1] != csr.n_vertices:
-        bounds.append(csr.n_vertices)
-    return [(bounds[i], bounds[i + 1]) for i in range(len(bounds) - 1)]
+    (GraphHandle.partition_plan's cut rule, for an in-memory CSR)."""
+    return edge_balanced_ranges(csr.offsets, n_parts)
 
 
 def _normalized_shares(shares, process_count: int) -> np.ndarray:

@@ -189,7 +189,8 @@ NAMESPACE = {
     "stream": (
         "partitions", "vertices", "edges", "decode_mode",
         "decode_reason", "plan_s", "plan_underlying_reads",
-        "plan_underlying_bytes", "underlying_reads", "underlying_bytes",
+        "plan_underlying_bytes", "plan_bytes_served", "underlying_reads",
+        "underlying_bytes",
         "cache_hits", "cache_misses", "readahead_blocks", "read_s",
         "handoff_wait_s", "stage_wait_s", "pad_s", "pad_bytes", "h2d_s",
         "ready_s", "bytes_h2d",

@@ -103,9 +103,11 @@ def test_stream_surfaces_storage_error_not_hang(graph_file, faulty_storage):
     path, csr = graph_file
     with paragrapher.open_graph(path, use_pgfuse=True,
                                 pgfuse_block_size=BLOCK) as g:
-        # the offsets sit in PG-Fuse before the fault is armed, so the
-        # stream's plan reads nothing and the first call storage sees is
-        # a producer's (arming it after the stream starts raced them)
+        # this plan loads every block of the offsets array before the
+        # fault is armed; the stream's plan pins those blocks again and
+        # probes a few offsets in them, so it calls storage for nothing
+        # and the first call storage sees is a producer's (arming the
+        # fault after the stream starts raced them)
         g.partition_plan(4)
         faulty_storage.fail_at[1] = OSError(errno.EIO, "flaky OST")
         faulty_storage.install_graph(g)

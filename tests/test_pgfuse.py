@@ -262,6 +262,48 @@ def test_readahead_under_eviction_budget(datafile):
         assert fs.resident_bytes <= 3 * bs
 
 
+@pytest.mark.parametrize("readahead", [0, 2])
+def test_pinned_span_makes_preads_requests_and_holds_its_blocks(datafile,
+                                                                readahead):
+    """Pinning a span asks storage for what a pread of it asks, serves no
+    bytes, and keeps every block resident under a cap with room for the
+    span and one readahead run, even while reads elsewhere fill the cap,
+    so reads inside it in any order cost no further call."""
+    path, data = datafile
+    bs, lo, hi = 2048, 5000, 40000
+    cap = ((hi - 1) // bs - lo // bs + 1 + readahead) * bs
+    files = [pgfuse.CachedFile(path, block_size=bs, readahead=readahead,
+                               max_resident_bytes=cap) for _ in range(2)]
+    whole, pinned = files
+    try:
+        assert whole.pread(lo, hi - lo) == data[lo:hi]
+        with pinned.pinned(lo, hi - lo) as held:
+            assert held
+            assert pinned.stats.underlying_reads == \
+                whole.stats.underlying_reads
+            assert pinned.stats.underlying_bytes == \
+                whole.stats.underlying_bytes
+            assert pinned.stats.bytes_served == 0
+            # a read past the span puts the file over its cap: the
+            # sweep must find its victims outside the pinned blocks
+            far = hi + 8 * bs
+            assert pinned.pread(far, 8) == data[far:far + 8]
+            calls = pinned.stats.underlying_reads
+            for off in range(hi - 8, lo, -997):
+                assert pinned.pread(off, 8) == data[off:off + 8]
+            assert pinned.stats.underlying_reads == calls
+        assert not (pinned._statuses.snapshot() > 0).any()  # all released
+        # a cap with no room pins nothing and fetches nothing
+        tight = pgfuse.CachedFile(path, block_size=bs, readahead=readahead,
+                                  max_resident_bytes=cap - bs)
+        files.append(tight)
+        with tight.pinned(lo, hi - lo) as held:
+            assert not held and tight.stats.underlying_reads == 0
+    finally:
+        for cf in files:
+            cf.close()
+
+
 def test_underlying_read_count_vs_naive(datafile):
     """The point of §III: far fewer underlying calls than consumer reads."""
     path, data = datafile

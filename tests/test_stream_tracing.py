@@ -4,10 +4,12 @@ read/pad/h2d/ready span per partition, stage timers that add up to
 ``decode_s``, the plan's storage reads counted apart, the same shards
 under every tracer, and the spans on the profiler's clock."""
 
+import math
+
 import numpy as np
 import pytest
 
-from repro.core import paragrapher
+from repro.core import compbin, paragrapher
 from repro.data.graph_stream import (StreamStats, assemble_csr,
                                      stream_partitions)
 from repro.graph import rmat
@@ -15,7 +17,7 @@ from repro.obs import PROFILER_TRACER, Tracer, verify_span_tree
 
 STAGES = ("stream.read", "stream.pad", "stream.h2d", "stream.ready")
 NEW_FIELDS = ("plan_s", "plan_underlying_reads", "plan_underlying_bytes",
-              "read_s", "handoff_wait_s", "stage_wait_s", "pad_s",
+              "plan_bytes_served", "read_s", "handoff_wait_s", "stage_wait_s", "pad_s",
               "pad_bytes", "h2d_s", "ready_s")
 
 
@@ -27,12 +29,21 @@ def graph_file(tmp_path_factory):
     return p, csr
 
 
-def _load(path, tracer=None, n_parts=5):
+def _mount(path, block_size=1 << 14, cap=None):
+    """The graph behind a fresh PG-Fuse mount, its file capped at
+    ``cap`` bytes when given."""
+    g = paragrapher.open_graph(path, use_pgfuse=True,
+                               pgfuse_block_size=block_size,
+                               pgfuse_readahead=1)
+    if cap is not None:
+        g.fs.set_file_budget(g.path, cap)
+    return g
+
+
+def _load(path, tracer=None, n_parts=5, block_size=1 << 14, cap=None):
     """One streamed load behind a fresh PG-Fuse mount: (shards on the
     host in vertex order, stats, plan, device shards)."""
-    with paragrapher.open_graph(path, use_pgfuse=True,
-                                pgfuse_block_size=1 << 14,
-                                pgfuse_readahead=1) as g:
+    with _mount(path, block_size, cap) as g:
         with stream_partitions(g, None, n_parts=n_parts,
                                tracer=tracer) as stream:
             shards = list(stream)
@@ -103,6 +114,57 @@ def test_plan_reads_counted_apart_and_the_same_with_any_tracer(graph_file):
     assert traced.plan_underlying_reads == plain.plan_underlying_reads
     assert traced.underlying_reads == plain.underlying_reads > 0
     assert traced.underlying_bytes == plain.underlying_bytes
+
+
+def _whole_array_read(path, block_size, cap=None):
+    """The graph file's PG-Fuse storage calls and bytes for reading its
+    offsets array whole on a fresh mount (the plan's old way)."""
+    with _mount(path, block_size, cap) as g:
+        before = g.pgfuse_file_stats()
+        with compbin.CompBinFile(g.fs.open(path)) as rdr:
+            rdr.offsets()
+        after = g.pgfuse_file_stats()
+    return (after.underlying_reads - before.underlying_reads,
+            after.underlying_bytes - before.underlying_bytes)
+
+
+@pytest.mark.parametrize("block_size", [1 << 14, 1 << 10])
+def test_plan_reads_only_the_offsets_it_probes(graph_file, block_size):
+    """The plan bisects the offsets in place: it reads the header, the
+    last offset and at most ``ceil(log2(V + 2))`` offsets of 8 bytes a
+    cut, while storage sees the calls and bytes a whole-array read of
+    the offsets makes on the same file and block size (the offsets span
+    3 blocks of 16 KiB, or 33 of 1 KiB)."""
+    path, csr = graph_file
+    n_parts, n_v = 5, csr.n_vertices
+    _, st, _, _ = _load(path, n_parts=n_parts, block_size=block_size)
+    bound = compbin.HEADER_SIZE + 8 * (
+        1 + n_parts * math.ceil(math.log2(n_v + 2)))
+    assert 0 < st.plan_bytes_served <= bound
+    assert st.plan_bytes_served * 50 < 8 * (n_v + 1)  # the whole array
+    calls, nbytes = _whole_array_read(path, block_size)
+    assert st.plan_underlying_reads == calls > 0
+    assert st.plan_underlying_bytes == nbytes
+    with paragrapher.open_graph(path) as g:
+        with stream_partitions(g, None, n_parts=n_parts) as stream:
+            list(stream)
+    assert stream.stats.plan_bytes_served == 0  # not mounted
+
+
+def test_a_cap_with_no_room_for_the_offsets_reads_them_whole(graph_file):
+    """Under a file cap that cannot hold the offsets' 33 blocks and a
+    readahead run, pinned blocks would crowd out the ones not yet
+    pinned (24 calls where a whole-array read makes 16): the plan reads
+    the array whole and makes the same calls."""
+    path, csr = graph_file
+    block, cap = 1 << 10, 16 << 10
+    _, st, plan, _ = _load(path, block_size=block, cap=cap)
+    assert st.plan_bytes_served >= 8 * (csr.n_vertices + 1)
+    calls, nbytes = _whole_array_read(path, block, cap)
+    assert (st.plan_underlying_reads, st.plan_underlying_bytes) == \
+        (calls, nbytes)
+    _, uncapped, uncapped_plan, _ = _load(path, block_size=block)
+    assert plan == uncapped_plan
 
 
 def test_shards_are_byte_equal_under_every_tracer(graph_file):
